@@ -167,7 +167,8 @@ dt = time.time() - t0
 lat = stats.latency_summary()
 print(f"\nserved {stats.requests_completed} requests / "
       f"{stats.tokens_generated} tokens in {dt:.1f}s "
-      f"({stats.tokens_generated / dt:.1f} tok/s on CPU; "
+      f"({stats.tokens_generated / dt:.1f} tok/s on "
+      f"{jax.devices()[0].platform}; "
       f"{stats.prefill_chunks} prefill chunks + {stats.decode_steps} "
       f"decode steps, slot occupancy {stats.slot_occupancy:.0%})")
 print(f"TTFT p50/p95 = {lat['ttft_s']['p50']:.3f}/"

@@ -1,11 +1,9 @@
-"""Version-compatibility shims for the jax APIs this repo uses.
+"""One spelling for each jax mesh/sharding API this repo uses.
 
-The codebase targets the current jax mesh/sharding surface (``jax.set_mesh``,
-``jax.shard_map``, ``jax.sharding.AxisType``, ``get_abstract_mesh``); older
-runtimes (0.4.x) expose the same functionality under different names or not
-at all.  Everything here degrades gracefully: on old jax the helpers fall
-back to the experimental/legacy spellings, and purely-advisory features
-(axis types, ambient-mesh hints) become no-ops rather than hard errors.
+The codebase targets the current jax mesh/sharding surface
+(``jax.make_mesh`` with axis types, ``jax.set_mesh``, ``jax.shard_map``,
+``jax.sharding.get_abstract_mesh``); these helpers fix the arguments the
+repo always passes.
 """
 from __future__ import annotations
 
@@ -21,54 +19,35 @@ __all__ = [
     "get_abstract_mesh",
 ]
 
-AXIS_TYPE_AUTO = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
+AXIS_TYPE_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_mesh(shape, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the runtime supports
-    them (newer jax requires explicit types for shard_map interop; old jax
-    has no such concept)."""
+    """``jax.make_mesh`` with Auto axis types (explicit types are what
+    shard_map interop expects)."""
     kwargs = {} if devices is None else {"devices": devices}
-    if AXIS_TYPE_AUTO is not None:
-        try:
-            return jax.make_mesh(
-                shape, axis_names,
-                axis_types=(AXIS_TYPE_AUTO,) * len(axis_names), **kwargs)
-        except TypeError:  # make_mesh predates axis_types
-            pass
-    return jax.make_mesh(shape, axis_names, **kwargs)
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AXIS_TYPE_AUTO,) * len(axis_names),
+                         **kwargs)
 
 
 @contextlib.contextmanager
 def set_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` when available, else the
-    legacy ``Mesh.__enter__`` context (same scoping semantics)."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Ambient-mesh context (``jax.set_mesh``)."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` (check_vma) or the experimental fallback
-    (check_rep) — the flag is the same knob under both names."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
+    """``jax.shard_map`` with ``check_vma`` named ``check``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def get_abstract_mesh():
-    """The ambient abstract mesh, or None when the runtime predates the
-    concept (callers treat None as "no ambient mesh, skip the hint")."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        return None
-    mesh = fn()
+    """The ambient abstract mesh, or None when no mesh with axes is set
+    (callers treat None as "no ambient mesh, skip the hint")."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not getattr(mesh, "axis_names", ()):
         return None
     return mesh

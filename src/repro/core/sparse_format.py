@@ -490,8 +490,9 @@ def pack_bucketed_stack(
     perm = np.full((n_layers, r_pad), -1, dtype=np.int64)
     inv_perm = np.zeros((n_layers, n_rows), dtype=np.int64)
     counts = np.zeros((n_layers, halves, r_pad, n_chunks), dtype=np.int64)
-    cells: list = [[[None] * r_pad for _ in range(halves)]
-                   for _ in range(n_layers)]
+    # per (layer, half): every cell's packed row, global column and value,
+    # row-major with columns ascending inside a row
+    cells: list = [[None] * halves for _ in range(n_layers)]
     nnz_per_half = np.zeros((halves, n_layers), dtype=np.int64)
 
     for l in range(n_layers):
@@ -505,12 +506,13 @@ def pack_bucketed_stack(
         inv_perm[l, perm_rows] = np.arange(n_rows, dtype=np.int64)
         for h, m in enumerate(ms):
             nnz_per_half[h, l] = int((m != 0).sum())
-            for i in range(n_rows):
-                src = perm_rows[i]
-                (nz,) = np.nonzero(m[src])
-                order, cnt = chunk_cells(nz, cc, n_chunks)
-                cells[l][h][i] = (nz[order], m[src, nz][order])
-                counts[l, h, i] = cnt
+            mp = m[perm_rows]
+            nz_r, nz_c = np.nonzero(mp)
+            cells[l][h] = (nz_r.astype(np.int32), nz_c.astype(np.int32),
+                           mp[nz_r, nz_c].astype(np.float32))
+            counts[l, h] = np.bincount(
+                nz_r * n_chunks + nz_c // cc,
+                minlength=r_pad * n_chunks).reshape(r_pad, n_chunks)
 
     widths = counts.reshape(
         n_layers, halves, r_pad // group, group, n_chunks).max(axis=(0, 1, 3, 4))
@@ -518,27 +520,28 @@ def pack_bucketed_stack(
                               n_buckets=n_buckets,
                               width_multiple=width_multiple)
 
-    buckets = []
-    for (row0, row1, lc) in plan.boundaries:
-        rg = row1 - row0
-        values = np.zeros((n_layers, halves * rg, n_chunks, lc), np.float32)
-        cols = np.zeros((n_layers, halves * rg, n_chunks, lc), np.int32)
-        valid = np.zeros((n_layers, halves * rg, n_chunks, lc), bool)
-        for l in range(n_layers):
-            for h in range(halves):
-                for i in range(row0, min(row1, n_rows)):
-                    c, v = cells[l][h][i]
-                    r = h * rg + (i - row0)
-                    off = 0
-                    for k in range(n_chunks):
-                        n = int(counts[l, h, i, k])
-                        if n:
-                            seg = slice(off, off + n)
-                            values[l, r, k, :n] = v[seg]
-                            cols[l, r, k, :n] = c[seg] - k * cc
-                            valid[l, r, k, :n] = True
-                            off += n
-        buckets.append({"values": values, "cols": cols, "valid": valid})
+    buckets = [{"values": np.zeros(shape, np.float32),
+                "cols": np.zeros(shape, np.int32),
+                "valid": np.zeros(shape, bool)}
+               for shape in ((n_layers, halves * (r1 - r0), n_chunks, lc)
+                             for r0, r1, lc in plan.boundaries)]
+    for l in range(n_layers):
+        for h in range(halves):
+            r, c, v = cells[l][h]
+            cells[l][h] = None
+            # the SDDS chunk pass (``chunk_cells``) for every row at once:
+            # a cell's slot is its rank among its row's cells of the same
+            # chunk (stable, so columns stay ascending)
+            key = r.astype(np.int64) * n_chunks + c // cc
+            cnt = counts[l, h].reshape(-1)
+            slot = np.arange(r.size) - (np.cumsum(cnt) - cnt)[key]
+            for b, (row0, row1, _) in zip(buckets, plan.boundaries):
+                sel = (r >= row0) & (r < row1)
+                idx = (l, h * (row1 - row0) + r[sel] - row0, c[sel] // cc,
+                       slot[sel])
+                b["values"][idx] = v[sel]
+                b["cols"][idx] = c[sel] % cc
+                b["valid"][idx] = True
 
     pack = BucketedStackedPack(
         buckets=buckets,
@@ -645,19 +648,18 @@ def projection_padded_slots(pack: BucketedStackedPack,
 def bucketed_stack_to_dense(pack: BucketedStackedPack, layer: int,
                             half: int) -> np.ndarray:
     """Inverse of ``pack_bucketed_stack`` for one (layer, half) — the
-    property-test oracle."""
+    property-test oracle and the dequantized-weights reconstruction."""
     w = np.zeros((pack.n_rows, pack.n_cols), dtype=np.float32)
     row0 = 0
     for b, rg in zip(pack.buckets, pack.bucket_rows):
-        for r in range(rg):
-            src = pack.perm[layer, row0 + r]
-            if src < 0:
-                continue
-            i = half * rg + r
-            for k in range(b["values"].shape[2]):
-                sel = b["valid"][layer, i, k]
-                w[src, b["cols"][layer, i, k, sel] + k * pack.chunk_cols] = \
-                    b["values"][layer, i, k, sel]
+        src = pack.perm[layer, row0:row0 + rg]
+        sel = slice(half * rg, (half + 1) * rg)
+        k = b["values"].shape[2]
+        cols = b["cols"][layer, sel] + (np.arange(k) * pack.chunk_cols
+                                        )[None, :, None]
+        ok = b["valid"][layer, sel] & (src >= 0)[:, None, None]
+        rows = np.broadcast_to(src[:, None, None], ok.shape)
+        w[rows[ok], cols[ok]] = b["values"][layer, sel][ok]
         row0 += rg
     return w
 

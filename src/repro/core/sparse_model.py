@@ -57,32 +57,49 @@ from repro.core.sparse_format import (BucketedStackedPack,
                                       compose_cols_with_pack, pack_group,
                                       projection_padded_slots)
 from repro.kernels import ops
+from repro.kernels.espim_spmv import kernel_planes, pack_planes
 from repro.models import transformer as T
 
 __all__ = ["sparsify_model", "sparsify_mlps", "pruned_param_tree",
-           "decode_step_sparse", "prefill_chunk_sparse", "sparse_stats",
-           "verify_sparse"]
+           "projection_arrays", "bucket_planes", "decode_step_sparse",
+           "prefill_chunk_sparse", "sparse_stats", "verify_sparse"]
 
 # the standard decoder-layer projections NOT covered by a group still
 # stream their dense bytes every decode token — sparse_stats charges them
 _DENSE_MODULES = ("attn", "mlp")
 
 
+def _kernel_bucket(values, cols, valid, halves: int) -> dict:
+    """One bucket's host planes (L, H*Rg, K, Lc) -> the kernels' plane
+    layout (L, K, Lc', H*Rgp) (``espim_spmv.kernel_planes``), with the
+    host ``valid`` mask carried into the same layout (pad slots False)."""
+    v, c = kernel_planes(np.asarray(values),
+                         np.asarray(cols, np.int32), halves=halves)
+    ok = kernel_planes(np.asarray(valid), np.asarray(valid),
+                       halves=halves)[0]
+    extra = c.shape[-2] - ok.shape[-2]        # int4: slots padded to 16
+    if extra:
+        ok = np.pad(ok, [(0, 0)] * (ok.ndim - 2) + [(0, extra), (0, 0)])
+    return {"values": jnp.asarray(v), "cols": jnp.asarray(c),
+            "valid": np.ascontiguousarray(ok)}
+
+
 def _to_device(pack: BucketedStackedPack) -> dict:
     """BucketedStackedPack -> the jnp dict the serving step consumes.
-    ``valid`` masks, nnz stats and the host QuantizedValuePlanes stay
-    host-side (stats/tests only); quantized packs upload per-bucket codes
-    (``q``) + pre-expanded per-row scales (``srow``, stacked over layers
-    like every other scan leaf) in place of the fp ``values``, and record
+
+    Bucket planes upload in the kernels' layout (L, K, Lc', H*Rgp) —
+    column chunk, ELL slot, packed row (each half lane-padded); the
+    reference lowering reads them back through ``ops.espim_spmv_planes``.
+    ``valid`` masks (same layout), nnz stats and the host
+    QuantizedValuePlanes stay host-side (stats/tests only); quantized
+    packs upload per-bucket codes (``q``) + pre-expanded per-row scales
+    (``srow``, (L, H*Rg) in packed row order, stacked over layers like
+    every other scan leaf) in place of the fp ``values``, and record
     static ``quant`` meta (bits / effective group_rows / storage family)
     per bucket."""
     if pack.qplanes is None:
-        buckets = [
-            {"values": jnp.asarray(b["values"]),
-             "cols": jnp.asarray(b["cols"], jnp.int32),
-             "valid": b["valid"]}
-            for b in pack.buckets
-        ]
+        buckets = [_kernel_bucket(b["values"], b["cols"], b["valid"],
+                                  pack.halves) for b in pack.buckets]
         quant_meta = None
     else:
         # quantized serving never touches the fp plane: upload ONLY the
@@ -90,13 +107,13 @@ def _to_device(pack: BucketedStackedPack) -> dict:
         # path folds the whole dequant into ONE multiply per bucket) —
         # uploading the fp32 values just to drop them would transiently
         # hold 4-8x the quantized footprint on device
-        buckets = [
-            {"q": jnp.asarray(plane.device_codes()),     # (L, HR, K, Lc[/2])
-             "cols": jnp.asarray(b["cols"], jnp.int32),
-             "srow": jnp.asarray(plane.row_scales()),
-             "valid": b["valid"]}
-            for b, plane in zip(pack.buckets, pack.qplanes)
-        ]
+        buckets = []
+        for b, plane in zip(pack.buckets, pack.qplanes):
+            kb = _kernel_bucket(plane.device_codes(), b["cols"], b["valid"],
+                                pack.halves)
+            kb["q"] = kb.pop("values")
+            kb["srow"] = jnp.asarray(plane.row_scales())
+            buckets.append(kb)
         quant_meta = tuple(
             {"bits": p.bits, "group_rows": p.group_rows, "storage": p.storage}
             for p in pack.qplanes)
@@ -106,6 +123,7 @@ def _to_device(pack: BucketedStackedPack) -> dict:
         "n_cols": pack.n_cols,
         "r_pad": pack.r_pad,
         "chunk_cols": pack.chunk_cols,
+        "n_chunks": pack.n_chunks,
         "bucket_rows": pack.bucket_rows,
         "widths": pack.widths,
         "buckets": buckets,
@@ -119,11 +137,21 @@ def _to_device(pack: BucketedStackedPack) -> dict:
         "quant": quant_meta,
         "qplanes": pack.qplanes,
     }
-    # fingerprint the *device* form — nibble-packed quant codes, expanded
-    # srow scales and int32 perms differ byte-wise from the host pack, so
-    # the build-time pack fingerprint cannot stand in for the upload check
+    # fingerprint the *device* form — kernel-layout planes, nibble-packed
+    # quant codes, expanded srow scales and int32 perms differ byte-wise
+    # from the host pack, so the build-time pack fingerprint cannot stand
+    # in for the upload check
     g["plane_fingerprints"], g["fingerprint"] = _group_fingerprint(g)
     return g
+
+
+def bucket_planes(g: dict, gi: int) -> tuple:
+    """Bucket ``gi`` of a serving group back in the host-pack layout:
+    (values or int8 codes, cols), each (L, H*Rg, K, Lc)."""
+    b = g["buckets"][gi]
+    return pack_planes(b["values"] if "values" in b else b["q"], b["cols"],
+                       rows=g["bucket_rows"][gi], width=g["widths"][gi],
+                       halves=g["halves"])
 
 
 def _group_fingerprint(g: dict) -> tuple[dict, str]:
@@ -154,14 +182,14 @@ def _validate_group(name: str, g: dict) -> None:
     err = integrity.PackIntegrityError
     cc, n_cols = g["chunk_cols"], g["n_cols"]
     for gi, b in enumerate(g["buckets"]):
-        cols = np.asarray(b["cols"])
+        cols = np.asarray(b["cols"])                 # (L, K, Lc', H*Rgp)
         valid = np.asarray(b["valid"], bool)
         what = f"group {name!r} bucket {gi}"
         if cols.shape != valid.shape:
             raise err(f"{what}: cols/valid shape mismatch")
-        k = cols.shape[-2]
+        k = cols.shape[-3]
         lim = np.minimum(cc, n_cols - np.arange(k) * cc)
-        lim = lim.reshape((1,) * (cols.ndim - 2) + (k, 1))
+        lim = lim.reshape((1,) * (cols.ndim - 3) + (k, 1, 1))
         if (valid & ((cols < 0) | (cols >= lim))).any():
             raise err(f"{what}: index plane out of bounds for input dim "
                       f"{n_cols} (chunk_cols={cc})")
@@ -172,16 +200,18 @@ def _validate_group(name: str, g: dict) -> None:
             srow = np.asarray(b["srow"])
             if not bool(np.isfinite(srow).all()):
                 raise err(f"{what}: non-finite quant scales")
-            if srow.shape != cols.shape[:2]:
+            rows = g["halves"] * g["bucket_rows"][gi]
+            if srow.shape != (cols.shape[0], rows):
                 raise err(f"{what}: srow scale layout {srow.shape} does not "
-                          f"cover the packed rows {cols.shape[:2]}")
+                          f"cover the packed rows {(cols.shape[0], rows)}")
             qm = g["quant"][gi]
-            if cols.shape[1] % max(1, qm["group_rows"]):
+            if rows % max(1, qm["group_rows"]):
                 raise err(f"{what}: rows not divisible by scale "
                           f"group_rows={qm['group_rows']}")
             q = np.asarray(b["q"])
             if qm["storage"] == "nib4":
-                want = cols.shape[:-1] + ((cols.shape[-1] + 1) // 2,)
+                want = cols.shape[:-2] + (cols.shape[-2] // 2,
+                                          cols.shape[-1])
                 if q.dtype != np.uint8 or q.shape != want:
                     raise err(f"{what}: nibble-packed codes layout "
                               f"{q.dtype}{q.shape} != uint8{want}")
@@ -391,7 +421,10 @@ def sparsify_model(cfg: ModelConfig, params: dict, sparsity: float, *,
         "specs": tuple(by_name.values()),
         "groups": groups,
         "dense_proj_bytes": _uncovered_dense_bytes(params, covered),
-        "pruned": {n: jnp.asarray(w, dtypes[n]) for n, w in pruned.items()},
+        # cast on the host: a device-side cast would hold each f32 stack
+        # (2.7 GB for one granite-3-2b MLP projection) on the device
+        "pruned": {n: jnp.asarray(np.asarray(w).astype(dtypes[n]))
+                   for n, w in pruned.items()},
     }
     if qspec is not None:
         out["quant_spec"] = qspec
@@ -469,25 +502,19 @@ def _bucket_spmv(pack: dict, buf: tuple, g: int, xt: jnp.ndarray,
     ``epilogue="glu"`` fuses act(gate)·up into the launch (half-major
     gate+up bucket, DESIGN.md §15): the fused lowerings replay the exact
     op order of the unfused path — dequant-once then gate — so the output
-    is bit-identical, in one launch instead of three ops."""
+    is bit-identical on the reference path, in one launch instead of
+    three ops."""
+    kw = dict(chunk_cols=pack["chunk_cols"], rows=pack["bucket_rows"][g],
+              width=pack["widths"][g], halves=pack["halves"], impl=impl)
     if pack["quant"] is not None:
         codes, cols, srow = buf
         if epilogue == "glu":
-            return ops.espim_spmv_batched_quant(
-                codes, cols, None, xt, chunk_cols=pack["chunk_cols"],
-                group_rows=pack["quant"][g]["group_rows"], impl=impl,
-                epilogue="glu", act=act, srow=srow)
-        yp = ops.espim_spmv_batched_quant(
-            codes, cols, None, xt, chunk_cols=pack["chunk_cols"],
-            group_rows=pack["quant"][g]["group_rows"], impl=impl)
-        return yp * srow[:, None]
+            return ops.espim_spmv_planes(codes, cols, xt, srow=srow,
+                                         epilogue="glu", act=act, **kw)
+        return ops.espim_spmv_planes(codes, cols, xt, **kw) * srow[:, None]
     vals, cols = buf
-    if epilogue == "glu":
-        return ops.espim_spmv_batched(vals, cols, xt,
-                                      chunk_cols=pack["chunk_cols"],
-                                      impl=impl, epilogue="glu", act=act)
-    return ops.espim_spmv_batched(vals, cols, xt,
-                                  chunk_cols=pack["chunk_cols"], impl=impl)
+    return ops.espim_spmv_planes(vals, cols, xt, epilogue=epilogue, act=act,
+                                 **kw)
 
 
 def _group_apply(pack: dict, gb: dict, xt: jnp.ndarray, impl: str) -> list:
@@ -610,10 +637,13 @@ def _pruned_mlp(cfg: ModelConfig, sparse: dict, wl: dict, hn: jnp.ndarray
     return L.mlp_relu2(hn, wl["w_up"], wl["w_down"], cfg.activation)
 
 
-def _proj_xs(sparse: dict, proj_path: str):
-    """Per-layer projection inputs threaded through the scan: the pack
+def projection_arrays(sparse: dict, proj_path: str = "kernel") -> dict:
+    """The per-layer projection arrays the layer scan threads: the pack
     buffers for the kernel path, the pruned dense copies for the GEMM
-    path."""
+    path.  Jitted serving steps take this pytree as an argument
+    (``proj=``), so the weights stay device buffers and never become
+    constants of the compiled program; everything else about the packs
+    is static geometry the step closes over."""
     if proj_path == "kernel":
         return _scan_bufs(sparse)
     if proj_path != "dense":
@@ -623,7 +653,8 @@ def _proj_xs(sparse: dict, proj_path: str):
 
 def _layer_stack(cfg: ModelConfig, params: dict, sparse: dict, cache: dict,
                  h, attn_step, attn_core, impl: str, unroll: bool,
-                 proj_path: str = "kernel", epilogue: bool = True):
+                 proj_path: str = "kernel", epilogue: bool = True,
+                 proj: dict | None = None):
     """Shared layer loop for decode/prefill: scan by default; ``unroll``
     keeps the per-layer Python loop as the parity reference.
 
@@ -633,6 +664,9 @@ def _layer_stack(cfg: ModelConfig, params: dict, sparse: dict, cache: dict,
     attention) wrapped by the packed QKV / O groups when it does.  The
     MLP is symmetric: uncovered (``projections="attn"``) it runs dense
     from the layer params on both proj paths.
+
+    ``proj`` is ``projection_arrays(sparse, proj_path)`` passed in by a
+    jitted caller; ``None`` reads it from ``sparse`` (closure constants).
     """
     attn_sparse = sparse.get("attn_sparse", False)
     mlp_sparse = sparse.get("mlp_sparse", "gateup" in sparse["groups"])
@@ -664,8 +698,9 @@ def _layer_stack(cfg: ModelConfig, params: dict, sparse: dict, cache: dict,
             h = h + _pruned_mlp(cfg, sparse, px, hn)
         return h, (kc, vc)
 
-    xs = (params["layers"], cache["k"], cache["v"],
-          _proj_xs(sparse, proj_path))
+    if proj is None:
+        proj = projection_arrays(sparse, proj_path)
+    xs = (params["layers"], cache["k"], cache["v"], proj)
     if unroll:
         k_new, v_new = [], []
         for i in range(cfg.n_layers):
@@ -679,7 +714,8 @@ def _layer_stack(cfg: ModelConfig, params: dict, sparse: dict, cache: dict,
 
 def decode_step_sparse(cfg: ModelConfig, params: dict, sparse: dict,
                        cache: dict, batch: dict, impl: str = "ref",
-                       unroll: bool = False, epilogue: bool = True):
+                       unroll: bool = False, epilogue: bool = True,
+                       proj: dict | None = None):
     """transformer.decode_step with ESPIM-format projections — every
     per-token MV runs through the packed kernels when ``sparse`` covers
     the whole layer (``sparsify_model``), or just the MLPs when it was
@@ -687,7 +723,8 @@ def decode_step_sparse(cfg: ModelConfig, params: dict, sparse: dict,
 
     ``epilogue=True`` (default) runs the gate+up MLP buckets with the
     act(gate)·up epilogue fused into the SpMV launch; ``epilogue=False``
-    is the bit-identical unfused reference (tests assert the parity)."""
+    is the bit-identical unfused reference (tests assert the parity).
+    ``proj``: ``projection_arrays(sparse)``, as a jit argument."""
     tokens = batch["tokens"]
     h = T.embed_tokens(cfg, params, tokens)
 
@@ -701,7 +738,7 @@ def decode_step_sparse(cfg: ModelConfig, params: dict, sparse: dict,
 
     h, k_new, v_new = _layer_stack(cfg, params, sparse, cache, h, attn_step,
                                    attn_core, impl, unroll,
-                                   epilogue=epilogue)
+                                   epilogue=epilogue, proj=proj)
     logits = T.logits_from_hidden(cfg, params, h)
     new_cache = {"k": k_new, "v": v_new, "len": cache["len"] + 1}
     return logits, new_cache
@@ -710,7 +747,7 @@ def decode_step_sparse(cfg: ModelConfig, params: dict, sparse: dict,
 def prefill_chunk_sparse(cfg: ModelConfig, params: dict, sparse: dict,
                          cache: dict, batch: dict, impl: str = "ref",
                          unroll: bool = False, proj_path: str = "dense",
-                         epilogue: bool = True):
+                         epilogue: bool = True, proj: dict | None = None):
     """transformer.prefill_chunk for the ESPIM-format engine: a C-token
     chunk lands at cache["len"]..  Same contract as
     ``factory.prefill_chunk``.
@@ -721,7 +758,8 @@ def prefill_chunk_sparse(cfg: ModelConfig, params: dict, sparse: dict,
     dense copies (bit-identical matrices, compute-bound phase) for every
     covered projection — attention included when the group set covers it;
     ``"kernel"`` feeds the fused packs with B*C columns (the MV datapath,
-    used by the parity tests and on PIM-like backends)."""
+    used by the parity tests and on PIM-like backends).  ``proj``:
+    ``projection_arrays(sparse, proj_path)``, as a jit argument."""
     tokens = batch["tokens"]
     start = cache["len"]
     n_valid = batch.get("n_valid")
@@ -738,7 +776,8 @@ def prefill_chunk_sparse(cfg: ModelConfig, params: dict, sparse: dict,
 
     h, k_new, v_new = _layer_stack(cfg, params, sparse, cache, h, attn_step,
                                    attn_core, impl, unroll,
-                                   proj_path=proj_path, epilogue=epilogue)
+                                   proj_path=proj_path, epilogue=epilogue,
+                                   proj=proj)
     logits = T.logits_from_hidden(cfg, params, h)
     new_cache = {"k": k_new, "v": v_new, "len": start + n_valid}
     return logits, new_cache
@@ -778,7 +817,7 @@ def _pack_stats(p: dict) -> dict:
         "bucket_rows": list(p["bucket_rows"]),
         "bucket_widths": list(p["widths"]),
         "single_bucket_pad_frac": 1 - p["nnz"] / max(
-            1, p["plan"].single_bucket_slots * p["buckets"][0]["cols"].shape[2]
+            1, p["plan"].single_bucket_slots * p["n_chunks"]
             * p["halves"] * n_layers),
         "value_plane_bytes": vbytes,
         "index_plane_bytes": ibytes,

@@ -27,6 +27,7 @@ from repro.core.sdds import KernelSchedule
 from repro.core.sparse_format import ELLChunkedPack, ELLPack, chunk_pack
 from repro.kernels import ref as _ref
 from repro.kernels.dense_mv import dense_mv_pallas
+from repro.kernels import espim_spmv as _k
 from repro.kernels.espim_spmv import (espim_spmv_batched_glu_pallas,
                                       espim_spmv_batched_pallas,
                                       espim_spmv_batched_quant_glu_pallas,
@@ -40,6 +41,7 @@ __all__ = [
     "espim_spmv",
     "espim_spmv_batched",
     "espim_spmv_batched_quant",
+    "espim_spmv_planes",
     "dense_mv",
     "espim_matvec",
     "EspimWeights",
@@ -345,6 +347,43 @@ def espim_spmv_batched_quant(values, cols, scales, x, *,
     return espim_spmv_batched_quant_pallas(
         values, cols, scales, x, chunk_cols=cc, group_rows=group_rows,
         interpret=_interpret(), **_block_kw(schedule))
+
+
+def espim_spmv_planes(values, cols, x, *, chunk_cols: int, rows: int,
+                      width: int, halves: int = 1,
+                      epilogue: str | None = None, act: str = "silu",
+                      srow=None, impl: str | None = None) -> jnp.ndarray:
+    """One bucket launch over kernel-layout planes (``_to_device`` of
+    ``core.sparse_model``; ``espim_spmv.kernel_planes``): x (M, B) ->
+    (halves * rows, B) f32 in packed row order, or act(gate) * up
+    (rows, B) for ``epilogue="glu"``.  int8 / uint8 planes are quantized
+    codes: the result is the code-domain accumulator, except that the GLU
+    epilogue dequantizes by ``srow`` (halves * rows,) before the gate.
+
+    ``width`` is the host pack's slot width Lc.  The reference lowering
+    reads the planes back into the host layout (``pack_planes``) and runs
+    the same ``ref.py`` function as the host-layout ops, so its result is
+    the one those ops give on the host pack."""
+    impl = _resolve(impl)
+    quant = jnp.dtype(values.dtype) in (jnp.dtype(jnp.int8),
+                                        jnp.dtype(jnp.uint8))
+    if impl == "pallas":
+        return _k.espim_spmv_planes(
+            values, cols, x, srow if epilogue == "glu" else None,
+            chunk_cols=chunk_cols, rows=rows, halves=halves,
+            epilogue=epilogue, act=act, interpret=_interpret())
+    v, c = _k.pack_planes(values, cols, rows=rows, width=width,
+                          halves=halves)
+    if quant:
+        if epilogue == "glu":
+            return _ref.espim_spmv_batched_chunked_quant_glu_ref(
+                v, c, srow, x, chunk_cols, act)
+        return _ref.espim_spmv_batched_chunked_quant_ref(
+            v, c, None, x, chunk_cols, 1)
+    if epilogue == "glu":
+        return _ref.espim_spmv_batched_chunked_glu_ref(v, c, x, chunk_cols,
+                                                       act)
+    return _ref.espim_spmv_batched_chunked_ref(v, c, x, chunk_cols)
 
 
 def dense_mv(w, x, *, impl: str | None = None) -> jnp.ndarray:

@@ -297,16 +297,21 @@ class ServeEngine:
                 self.cache.state_names, sparse=sparse, impl=impl)
 
         if sparse is None:
+            self._step_params = params
             self._decode = jax.jit(_finite_step(
                 lambda p, c, b: serve_step_fn(cfg, p, c, b,
                                               temperature=temperature)))
         else:
-            # ESPIM-format decode: the packs are closure constants so the
-            # fused kernel sees static chunk geometry
+            # ESPIM-format decode: the pack buffers ride in as an argument
+            # next to the params (device buffers, never constants of the
+            # compiled step); only the static chunk geometry is closed over
+            self._step_params = {
+                "params": params,
+                "proj": sparse_model.projection_arrays(sparse)}
             self._decode = jax.jit(_finite_step(
                 lambda p, c, b: serve_step_sparse_fn(
-                    cfg, p, sparse, c, b, temperature=temperature,
-                    impl=impl)))
+                    cfg, p["params"], sparse, c, b, temperature=temperature,
+                    impl=impl, proj=p["proj"])))
         # lazily-built dense fallback for quarantined slots: jitted over
         # the pruned dense copy of the same weights, so its greedy tokens
         # match the sparse path's (PR3-5 parity) — degraded is slower,
@@ -836,7 +841,7 @@ class ServeEngine:
             try:
                 with self.tracer.span("decode.launch", cat="decode"):
                     nxt, ok, new_cache = self._retry(
-                        self._decode, self.params, view, batch)
+                        self._decode, self._step_params, view, batch)
                     self.tracer.fence(ok)
             except TransientStepError:
                 for i in list(healthy):

@@ -137,8 +137,8 @@ def inject_poisoned_decode(eng: ServeEngine, sparse_bad: dict) -> None:
     fallback reconstructs uncontaminated weights)."""
     cfg, temperature, impl = eng.cfg, eng.temperature, eng.impl
     eng._decode = jax.jit(_finite_step(
-        lambda p, c, b: serve_step_sparse_fn(cfg, p, sparse_bad, c, b,
-                                             temperature=temperature,
+        lambda p, c, b: serve_step_sparse_fn(cfg, p["params"], sparse_bad,
+                                             c, b, temperature=temperature,
                                              impl=impl)))
 
 

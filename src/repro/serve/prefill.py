@@ -50,12 +50,16 @@ class ChunkedPrefiller:
         self.seq_names = list(seq_names)
         self.state_names = list(state_names)
         if sparse is None:
+            self._proj = None
             self._fn = jax.jit(
-                lambda p, c, b: factory.prefill_chunk(cfg, p, c, b))
+                lambda p, x, c, b: factory.prefill_chunk(cfg, p, c, b))
         else:
+            # the pruned dense copies ride in as an argument (device
+            # buffers, never constants of the compiled chunk step)
+            self._proj = sparse_model.projection_arrays(sparse, "dense")
             self._fn = jax.jit(
-                lambda p, c, b: sparse_model.prefill_chunk_sparse(
-                    cfg, p, sparse, c, b, impl=impl))
+                lambda p, x, c, b: sparse_model.prefill_chunk_sparse(
+                    cfg, p, sparse, c, b, impl=impl, proj=x))
 
     def run_chunk(self, params, pf_cache, prompt, pos: int):
         """Prefill one chunk starting at ``pos``.  Returns (full-chunk
@@ -66,7 +70,8 @@ class ChunkedPrefiller:
         tokens[0, :n_valid] = prompt[pos : pos + n_valid]
         batch = {"tokens": jnp.asarray(tokens),
                  "n_valid": jnp.asarray([n_valid], jnp.int32)}
-        logits, pf_cache = self._fn(params, pf_cache, batch)
+        logits, pf_cache = self._fn(params, self._proj, pf_cache,
+                                    batch)
         return logits, pf_cache, n_valid
 
     def chunk_rows(self, pf_cache: dict, pos: int) -> dict:

@@ -58,7 +58,8 @@ def serve_step_fn(cfg: ModelConfig, params, cache: dict, batch: dict,
 
 def serve_step_sparse_fn(cfg: ModelConfig, params, sparse: dict,
                          cache: dict, batch: dict,
-                         temperature: float = 0.0, impl: str = "ref"):
+                         temperature: float = 0.0, impl: str = "ref",
+                         proj: dict | None = None):
     """ESPIM-format decode step: one scanned layer stack whose covered
     projections run from the width-bucketed pack groups — the fused QKV
     launch + static take, the packed O projection, the fused gate+up
@@ -70,9 +71,11 @@ def serve_step_sparse_fn(cfg: ModelConfig, params, sparse: dict,
     scale leaves) through the quantized kernels — section 9.
 
     Same contract as ``serve_step_fn``: (next_tokens, logits, new_cache).
+    ``proj`` (``sparse_model.projection_arrays``) carries the pack
+    buffers as a jit argument; ``None`` closes over those in ``sparse``.
     """
     logits, cache = sparse_model.decode_step_sparse(
-        cfg, params, sparse, cache, batch, impl=impl)
+        cfg, params, sparse, cache, batch, impl=impl, proj=proj)
     return _sample_next(cfg, logits, batch, temperature), logits, cache
 
 

@@ -250,12 +250,14 @@ def sparse_pack_pspecs(sparse: dict, mesh: Mesh):
     matching the jnp leaves of ``sparse["groups"]``.
     """
     def bucket_spec(b):
+        # packed rows are the minor (lane) dim of the kernel planes
+        # (L, K, Lc, H*Rgp) and of the (L, H*Rg) scales
         out = {}
         for key in ("values", "q", "cols", "srow"):
             if key in b:
                 shape = b[key].shape
-                axes = (None, _fit(mesh, shape[1], "model"))
-                out[key] = P(*axes, *(None,) * (len(shape) - 2))
+                out[key] = P(*(None,) * (len(shape) - 1),
+                             _fit(mesh, shape[-1], "model"))
         return out
 
     return {

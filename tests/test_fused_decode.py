@@ -17,8 +17,9 @@ from repro.core import sparse_model as SM
 from repro.core.pruning import magnitude_prune
 from repro.core.sparse_format import (bucketed_stack_to_dense,
                                       pack_bucketed_stack, pack_ell_chunked)
-from repro.core.sparse_model import (decode_step_sparse, prefill_chunk_sparse,
-                                     sparse_stats, sparsify_mlps)
+from repro.core.sparse_model import (bucket_planes, decode_step_sparse,
+                                     prefill_chunk_sparse, sparse_stats,
+                                     sparsify_mlps)
 from repro.kernels import ops, ref
 from repro.kernels.espim_spmv import espim_spmv_batched_pallas
 from repro.models import factory
@@ -85,8 +86,9 @@ def test_fused_gateup_matches_separate_spmv():
     # fused: one SpMV per bucket, halves split in packed order, then
     # mapped back to logical rows for the comparison
     packed = []
-    for b, rg in zip(gu["buckets"], gu["bucket_rows"]):
-        yp = ops.espim_spmv_batched(b["values"][l], b["cols"][l], x,
+    for gi, rg in enumerate(gu["bucket_rows"]):
+        vals, cols = bucket_planes(gu, gi)
+        yp = ops.espim_spmv_batched(vals[l], cols[l], x,
                                     chunk_cols=gu["chunk_cols"], impl="ref")
         packed.append((yp[:rg], yp[rg:]))
     gate_p = jnp.concatenate([g for g, _ in packed], axis=0)

@@ -142,12 +142,12 @@ def test_sparse_pack_pspecs_shard_packed_rows():
                 arr = b[key]
                 assert len(spec) == arr.ndim
                 assert spec[0] is None          # layer-stack dim: the scan
-                row_ax = spec[1]
+                row_ax = spec[-1]               # packed rows: the lane dim
                 assert row_ax in (None, "model")
                 if row_ax == "model":
-                    assert arr.shape[1] % partition.mesh_axis_size(
+                    assert arr.shape[-1] % partition.mesh_axis_size(
                         MESH, "model") == 0
-                assert all(a is None for a in spec[2:])  # chunk/width dims
+                assert all(a is None for a in spec[1:-1])  # chunk/slot dims
     # quantized packs: srow scales shard with their rows
     sq = sparsify_model(cfg, params, 0.9, projections="mlp", row_tile=32,
                         quant="int8")

@@ -1,0 +1,118 @@
+"""Compile the serving path's Pallas kernels for a described TPU v5e.
+
+Interpret mode on the CPU runs a kernel's arithmetic but not Mosaic's
+rules (block tiling, gather forms, VMEM), so these tests lower each
+kernel the granite-3-2b serving path launches, at its real bucket shapes,
+with the TPU compiler for a v5e that is described, not attached.  The
+topology is described inside a fixture (never at import), and the
+persistent compilation cache is off around the compiles: an entry written
+for a described chip cannot be read back here.
+
+The last test checks, on the CPU, that the jitted sparse decode step
+takes the packs as arguments: no pack-sized constant in its program.
+"""
+import functools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.kernels.espim_spmv import LANE, espim_spmv_planes
+
+B = 4            # decode slots
+CHUNK_COLS = 512
+
+# granite-3-2b bucket geometry at 90% sparsity, int8 packs
+# (rows per half, halves, column chunks, slot width Lc)
+INT8_BATCHED = [(1344, 1, 4, 72),      # qkv, d_model -> q|k|v
+                (1696, 1, 16, 80)]     # down, d_ff -> d_model
+INT8_GLU = [(4064, 2, 4, 80),          # gate+up halves, d_model -> d_ff
+            (2112, 2, 4, 72)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    prev_log = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+        if prev_log is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+def _compile(one_chip, rows, halves, k, lc, vdtype, epilogue=None):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rp = halves * (-(-rows // LANE) * LANE)
+    lc_v = lc // 2 if vdtype == jnp.uint8 else lc
+    args = [sds((k, lc_v, rp), vdtype), sds((k, lc, rp), jnp.int32),
+            sds((k * CHUNK_COLS, B), jnp.float32)]
+    if epilogue == "glu" and vdtype in (jnp.int8, jnp.uint8):
+        args.append(sds((halves * rows,), jnp.float32))
+    fn = functools.partial(espim_spmv_planes, chunk_cols=CHUNK_COLS,
+                           rows=rows, halves=halves, epilogue=epilogue,
+                           interpret=False)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,halves,k,lc", INT8_BATCHED)
+def test_int8_batched_compiles(one_chip, rows, halves, k, lc):
+    _compile(one_chip, rows, halves, k, lc, jnp.int8)
+
+
+@pytest.mark.parametrize("rows,halves,k,lc", INT8_GLU)
+def test_int8_glu_compiles(one_chip, rows, halves, k, lc):
+    _compile(one_chip, rows, halves, k, lc, jnp.int8, epilogue="glu")
+
+
+def test_fp_batched_compiles(one_chip):
+    _compile(one_chip, 992, 1, 4, 72, jnp.float32)     # attn_out bucket
+
+
+def test_int4_batched_compiles(one_chip):
+    _compile(one_chip, 1344, 1, 4, 80, jnp.uint8)      # qkv, Lc' = 80
+
+
+def test_sparse_decode_step_takes_packs_as_arguments():
+    """The engine's jitted decode step over int8 packs lowers with the
+    pack planes as parameters: no constant in the program is as large as
+    the smallest pack plane."""
+    from repro.configs.registry import get_config
+    from repro.core.sparse_model import sparsify_model
+    from repro.models import factory
+    from repro.serve.engine import ServeEngine
+
+    cfg = get_config("granite-3-2b", reduced=True)
+    params = factory.init_params(cfg, jax.random.PRNGKey(0))
+    sparse = sparsify_model(cfg, params, 0.9, quant="int8")
+    eng = ServeEngine(cfg, params, batch_slots=B, max_len=64, sparse=sparse)
+    view = eng.cache.gather_view(np.zeros(B, np.int32))
+    batch = {"tokens": jnp.zeros((B, 1), jnp.int32), "rng": None}
+    text = eng._decode.lower(eng._step_params, view, batch).as_text()
+    smallest = min(a.size for g in sparse["groups"].values()
+                   for b in g["buckets"] for a in (b["q"], b["cols"]))
+    sizes = [int(np.prod([int(d) for d in dims.split("x")[:-1]] or [1]))
+             for dims in re.findall(r"stablehlo\.constant dense.*?: "
+                                    r"tensor<([0-9a-z]+(?:x[0-9a-z]+)*)>",
+                                    text)]
+    assert sizes, "expected the lowered step to hold some constants"
+    assert max(sizes) < smallest, (max(sizes), smallest)

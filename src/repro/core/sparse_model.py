@@ -530,6 +530,7 @@ def _group_take(gb: dict, parts: list) -> jnp.ndarray:
     return jnp.take(yp, gb["inv"], axis=0)
 
 
+@jax.named_scope("espim.qkv")
 def _fused_qkv(cfg: ModelConfig, sparse: dict, bufs: dict, attn_p: dict,
                hn: jnp.ndarray, impl: str):
     """The fused QKV pack: hn (B, T, D) -> q (B, T, H, hd), k/v
@@ -558,6 +559,7 @@ def _fused_qkv(cfg: ModelConfig, sparse: dict, bufs: dict, attn_p: dict,
             cut("wv", cfg.n_kv_heads))
 
 
+@jax.named_scope("espim.o")
 def _fused_o(cfg: ModelConfig, sparse: dict, bufs: dict,
              out_h: jnp.ndarray, impl: str) -> jnp.ndarray:
     """The packed O projection: attention heads (B, T, H, hd) -> residual
@@ -570,6 +572,7 @@ def _fused_o(cfg: ModelConfig, sparse: dict, bufs: dict,
     return y.T.reshape(b, t, -1).astype(out_h.dtype)
 
 
+@jax.named_scope("espim.qkv")
 def _pruned_qkv(cfg: ModelConfig, px: dict, attn_p: dict, hn: jnp.ndarray):
     """Dense-path QKV from the pruned copies (GEMM prefill, Section
     III-I): same matrices the packs hold, applied as GEMMs; biases come
@@ -603,25 +606,29 @@ def _fused_mlp(cfg: ModelConfig, sparse: dict, bufs: dict, hn: jnp.ndarray,
     b, t = hn.shape[0], hn.shape[1]
     xt = hn.reshape(-1, hn.shape[-1]).T.astype(jnp.float32)   # (in, B*T)
 
-    parts = []
-    if sparse["gated"] and epilogue:
-        for g, buf in enumerate(bufs["gateup"]["bufs"]):
-            parts.append(_bucket_spmv(gu, buf, g, xt, impl,
-                                      epilogue="glu", act=cfg.activation))
-    else:
-        for yp, rg in zip(_group_apply(gu, bufs["gateup"], xt, impl),
-                          gu["bucket_rows"]):
-            if sparse["gated"]:
-                # gate rows and up rows of the bucket share packed order:
-                # the product needs no unscatter (act(0)*0 == 0 on pad rows)
-                parts.append(act(yp[:rg]) * yp[rg:])
-            else:
-                parts.append(act(yp))
-    inter = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    with jax.named_scope("espim.gateup"):
+        parts = []
+        if sparse["gated"] and epilogue:
+            for g, buf in enumerate(bufs["gateup"]["bufs"]):
+                parts.append(_bucket_spmv(gu, buf, g, xt, impl,
+                                          epilogue="glu", act=cfg.activation))
+        else:
+            for yp, rg in zip(_group_apply(gu, bufs["gateup"], xt, impl),
+                              gu["bucket_rows"]):
+                if sparse["gated"]:
+                    # gate rows and up rows of the bucket share packed
+                    # order: the product needs no unscatter (act(0)*0 == 0
+                    # on pad rows)
+                    parts.append(act(yp[:rg]) * yp[rg:])
+                else:
+                    parts.append(act(yp))
+        inter = (parts[0] if len(parts) == 1
+                 else jnp.concatenate(parts, axis=0))
 
-    y = _group_take(bufs["down"],
-                    _group_apply(dn, bufs["down"], inter, impl))
-    return y.T.reshape(b, t, -1).astype(hn.dtype)             # (B, T, D)
+    with jax.named_scope("espim.down"):
+        y = _group_take(bufs["down"],
+                        _group_apply(dn, bufs["down"], inter, impl))
+        return y.T.reshape(b, t, -1).astype(hn.dtype)         # (B, T, D)
 
 
 def _pruned_mlp(cfg: ModelConfig, sparse: dict, wl: dict, hn: jnp.ndarray
@@ -679,13 +686,15 @@ def _layer_stack(cfg: ModelConfig, params: dict, sparse: dict, cache: dict,
                 q, k, v = _fused_qkv(cfg, sparse, px, lp["attn"], hn, impl)
             else:
                 q, k, v = _pruned_qkv(cfg, px, lp["attn"], hn)
-            a_h, kc, vc = attn_core(q, k, v, kc, vc)
+            with jax.named_scope("attention"):
+                a_h, kc, vc = attn_core(q, k, v, kc, vc)
             if proj_path == "kernel":
                 a = _fused_o(cfg, sparse, px, a_h, impl)
             else:
                 from repro.models import layers as L
                 b, t = hn.shape[0], hn.shape[1]
-                a = L.dense(a_h.reshape(b, t, -1), px["wo"])
+                with jax.named_scope("espim.o"):
+                    a = L.dense(a_h.reshape(b, t, -1), px["wo"])
         else:
             a, kc, vc, _, _ = attn_step(lp, hn, kc, vc)
         h = h + a

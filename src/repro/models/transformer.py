@@ -118,19 +118,20 @@ def attn_decode_core(cfg: ModelConfig, q, k, v, k_cache, v_cache, cache_len,
     # resharding — a per-batch DUS on a sequence-sharded cache triggers
     # XLA's "involuntary full rematerialization" copies (hillclimb iter 1,
     # EXPERIMENTS.md section Perf).
-    s_max = k_cache.shape[1]
-    at_pos = (jnp.arange(s_max, dtype=jnp.int32)[None, :]
-              == pos[:, None])[..., None, None]          # (B, S, 1, 1)
-    if k_scale is not None:
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        k_cache = jnp.where(at_pos, kq, k_cache)
-        v_cache = jnp.where(at_pos, vq, v_cache)
-        k_scale = jnp.where(at_pos[..., 0], ks, k_scale)
-        v_scale = jnp.where(at_pos[..., 0], vs, v_scale)
-    else:
-        k_cache = jnp.where(at_pos, k.astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(at_pos, v.astype(v_cache.dtype), v_cache)
+    with jax.named_scope("kv_cache"):
+        s_max = k_cache.shape[1]
+        at_pos = (jnp.arange(s_max, dtype=jnp.int32)[None, :]
+                  == pos[:, None])[..., None, None]      # (B, S, 1, 1)
+        if k_scale is not None:
+            kq, ks = _quantize_kv(k)
+            vq, vs = _quantize_kv(v)
+            k_cache = jnp.where(at_pos, kq, k_cache)
+            v_cache = jnp.where(at_pos, vq, v_cache)
+            k_scale = jnp.where(at_pos[..., 0], ks, k_scale)
+            v_scale = jnp.where(at_pos[..., 0], vs, v_scale)
+        else:
+            k_cache = jnp.where(at_pos, k.astype(k_cache.dtype), k_cache)
+            v_cache = jnp.where(at_pos, v.astype(v_cache.dtype), v_cache)
     out = L.attention_decode(q, k_cache, v_cache, pos + 1,
                              k_scale=k_scale, v_scale=v_scale)
     return out, k_cache, v_cache, k_scale, v_scale
@@ -188,16 +189,17 @@ def attn_prefill_core(cfg: ModelConfig, q, k, v, k_cache, v_cache, start,
         q, k = L.apply_mrope(q, k, positions3, cfg.rope_theta)
     elif not cfg.learned_pos:
         q, k = L.apply_rope(q, k, pos, cfg.rope_theta)
-    if k_scale is not None:
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        k_cache = splice_rows(k_cache, kq, start)
-        v_cache = splice_rows(v_cache, vq, start)
-        k_scale = splice_rows(k_scale, ks, start)
-        v_scale = splice_rows(v_scale, vs, start)
-    else:
-        k_cache = splice_rows(k_cache, k.astype(k_cache.dtype), start)
-        v_cache = splice_rows(v_cache, v.astype(v_cache.dtype), start)
+    with jax.named_scope("kv_cache"):
+        if k_scale is not None:
+            kq, ks = _quantize_kv(k)
+            vq, vs = _quantize_kv(v)
+            k_cache = splice_rows(k_cache, kq, start)
+            v_cache = splice_rows(v_cache, vq, start)
+            k_scale = splice_rows(k_scale, ks, start)
+            v_scale = splice_rows(v_scale, vs, start)
+        else:
+            k_cache = splice_rows(k_cache, k.astype(k_cache.dtype), start)
+            v_cache = splice_rows(v_cache, v.astype(v_cache.dtype), start)
     out = L.attention_prefill(q, k_cache, v_cache, pos,
                               k_scale=k_scale, v_scale=v_scale)
     return out, k_cache, v_cache, k_scale, v_scale
@@ -298,6 +300,7 @@ def embed_tokens(cfg: ModelConfig, params, tokens):
     return jnp.take(params["embed"], tokens, axis=0).astype(cfg.cdtype)
 
 
+@jax.named_scope("lm_head")
 def logits_from_hidden(cfg: ModelConfig, params, h):
     h = _norm(cfg, params["final_norm"], h)
     if cfg.tie_embeddings:

@@ -123,12 +123,14 @@ class PagedKVCache(_KVCacheBase):
 
     # ---------------------------------------------------------------- jits
     def _build_jits(self):
+        # the function names become the programs' XLA module names
+        # (``jit_kv_*``), which the device-trace readers match
         lx = {n: self.pages[n].shape[0] for n in self.seq_names}
         b, nb = self.b, self.num_blocks
         mb, bs = self.blocks_per_slot, self.block_size
 
         @jax.jit
-        def gather(pages, bt_flat):
+        def kv_gather_view(pages, bt_flat):
             out = {}
             for n, arena in pages.items():
                 v = jnp.take(arena, bt_flat, axis=1)
@@ -136,7 +138,7 @@ class PagedKVCache(_KVCacheBase):
             return out
 
         @jax.jit
-        def scatter_decode(pages, view, idx):
+        def kv_scatter_decode(pages, view, idx):
             # idx: (3, B) int32 rows = (lens, phys, off) — one device_put
             # per tick instead of three
             lens, phys, off = idx[0], idx[1], idx[2]
@@ -148,28 +150,28 @@ class PagedKVCache(_KVCacheBase):
             return out
 
         @jax.jit
-        def scatter_chunk(pages, rows, phys, off):
+        def kv_scatter_chunk(pages, rows, phys, off):
             return {n: pages[n].at[:, phys, off].set(rows[n], mode="drop")
                     for n in pages}
 
         @jax.jit
-        def mask_state(old, new, active):
+        def kv_mask_state(old, new, active):
             def leaf(o, nw):
                 m = active.reshape((1, b) + (1,) * (o.ndim - 2))
                 return jnp.where(m, nw.astype(o.dtype), o)
             return jax.tree.map(leaf, old, new)
 
         @jax.jit
-        def scrub(pages, idx):
+        def kv_scrub(pages, idx):
             # idx: (2,) int32 = (phys, off) — zero one row of every arena
             return {n: arena.at[:, idx[0], idx[1]].set(0)
                     for n, arena in pages.items()}
 
-        self._gather = gather
-        self._scatter_decode = scatter_decode
-        self._scatter_chunk = scatter_chunk
-        self._mask_state = mask_state
-        self._scrub = scrub
+        self._gather = kv_gather_view
+        self._scatter_decode = kv_scatter_decode
+        self._scatter_chunk = kv_scatter_chunk
+        self._mask_state = kv_mask_state
+        self._scrub = kv_scrub
 
     # ----------------------------------------------------------- allocator
     def blocks_needed(self, n_tokens: int) -> int:

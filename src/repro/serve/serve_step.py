@@ -42,6 +42,7 @@ def sample_tokens(cfg: ModelConfig, last, temperature: float, rng=None):
     return nxt.astype(jnp.int32)
 
 
+@jax.named_scope("lm_head")
 def _sample_next(cfg: ModelConfig, logits, batch: dict, temperature: float):
     """Sampling over the final position of decode logits (B, 1, V)."""
     nxt = sample_tokens(cfg, logits[:, -1, :], temperature,

@@ -30,8 +30,7 @@ from repro.telemetry.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                      REQUIRED_SERVE_METRICS, Registry,
                                      THROUGHPUT_BUCKETS, US_BUCKETS,
                                      log_buckets, validate_snapshot)
-from repro.telemetry.profile import (KernelProfiler,  # noqa: F401
-                                     LaunchTiming, time_launch)
+from repro.telemetry.profile import LaunchTiming, time_launch  # noqa: F401
 from repro.telemetry.regression import (MetricSpec,  # noqa: F401
                                         PerfRegressionError,
                                         assert_no_regression, compare,
@@ -51,7 +50,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "log_buckets",
     "LATENCY_BUCKETS_S", "THROUGHPUT_BUCKETS", "US_BUCKETS",
     "REQUIRED_SERVE_METRICS", "validate_snapshot",
-    "KernelProfiler", "LaunchTiming", "time_launch",
+    "LaunchTiming", "time_launch",
     "Span", "Tracer", "NULL_TRACER", "get_tracer", "set_tracer",
     "span_coverage", "phase_breakdown", "validate_chrome_trace",
     "BREAKDOWN_SCHEMA_KEYS",

@@ -8,9 +8,6 @@ returns best / p50 / p95 microseconds plus — when the caller passes the
 pack's streamed plane bytes — the effective GB/s the launch sustained
 and its fraction of the *dense roofline* (the bandwidth the dense matmul
 achieved on the same device: the paper's own yardstick, Section IV).
-
-``KernelProfiler`` accumulates launches keyed by (shape, impl, quant, B)
-so a bench or a serving process can dump one per-kernel report.
 """
 from __future__ import annotations
 
@@ -20,7 +17,7 @@ import time
 from repro.telemetry.metrics import US_BUCKETS, Histogram
 from repro.telemetry.trace import NULL_TRACER
 
-__all__ = ["LaunchTiming", "time_launch", "KernelProfiler"]
+__all__ = ["LaunchTiming", "time_launch"]
 
 
 @dataclasses.dataclass
@@ -103,23 +100,3 @@ def time_launch(fn, *args, iters: int = 5, warmup: int = 1,
             t.roofline_frac = t.gbps_best / max(dense_gbps, 1e-12)
     return t
 
-
-class KernelProfiler:
-    """Accumulates launch profiles keyed by (shape, impl, quant, B)."""
-
-    def __init__(self, tracer=NULL_TRACER):
-        self.tracer = tracer
-        self.records: dict[tuple, LaunchTiming] = {}
-
-    def profile(self, fn, *args, shape: str, impl: str = "ref",
-                quant: str = "fp", B: int = 1, **kw) -> LaunchTiming:
-        key = (shape, impl, quant, B)
-        t = time_launch(fn, *args, tracer=self.tracer,
-                        label=f"kernel:{shape}/{quant}/B{B}", **kw)
-        self.records[key] = t
-        return t
-
-    def report(self) -> dict:
-        return {
-            f"{shape}|impl={impl}|quant={quant}|B={b}": t.to_dict()
-            for (shape, impl, quant, b), t in sorted(self.records.items())}

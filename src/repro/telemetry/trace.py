@@ -27,9 +27,15 @@ Design constraints:
   at a span boundary **only while tracing** — with tracing disabled it
   is a no-op, so instrumentation never changes the untraced pipeline's
   overlap behavior.
+* **profiler sink.**  ``Tracer(profiler=True)`` records nothing itself:
+  each span opens a ``jax.profiler.TraceAnnotation`` of the span's exact
+  name, so the engine's phases land in the profiler's trace on the same
+  clock as the device's programs and ops.  The device timeline is then
+  read from that trace, so ``fence`` does not sync in this mode and
+  ``instant`` records nothing.
 
-This module is dependency-free (stdlib only; jax is imported lazily and
-only inside ``fence``).
+This module is dependency-free (stdlib only; jax is imported lazily, in
+``fence`` and by the profiler sink).
 """
 from __future__ import annotations
 
@@ -59,6 +65,27 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 _ids = itertools.count(1)
+
+
+class _AnnotatedSpan:
+    """A span of the profiler sink: one ``TraceAnnotation`` carrying the
+    span's name and nothing else (attributes would change the event name
+    that trace readers match), so ``set`` is a no-op."""
+    __slots__ = ("_ann",)
+
+    def __init__(self, annotation):
+        self._ann = annotation
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, key, value):
+        return self
 
 
 class Span:
@@ -103,8 +130,12 @@ class Span:
 
 
 class Tracer:
-    def __init__(self, enabled: bool = True):
+    def __init__(self, enabled: bool = True, profiler: bool = False):
         self.enabled = enabled
+        self._annotation = None
+        if enabled and profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.events: list[Span] = []     # closed spans, completion order
         self.instants: list[tuple] = []  # (name, cat, t_ns, tid, args)
         self._lock = threading.Lock()
@@ -114,9 +145,13 @@ class Tracer:
     # ------------------------------------------------------------ recording
     def span(self, name: str, cat: str | None = None, args: dict | None = None):
         """Context manager for one nested span.  Disabled tracers return
-        the shared null span: zero allocations on the hot path."""
+        the shared null span: zero allocations on the hot path.  The
+        profiler sink returns a ``TraceAnnotation`` named ``name``; ``cat``
+        and ``args`` are dropped."""
         if not self.enabled:
             return _NULL_SPAN
+        if self._annotation is not None:
+            return _AnnotatedSpan(self._annotation(name))
         stack = self._stack()
         parent = stack[-1] if stack else None
         return Span(self, name, cat, threading.get_ident(),
@@ -127,28 +162,19 @@ class Tracer:
                 args: dict | None = None) -> None:
         """A point event (trace_event ``ph:"i"``) — quarantines, retries,
         watchdog flags: things with a moment but no duration."""
-        if not self.enabled:
+        if not self.enabled or self._annotation is not None:
             return
         with self._lock:
             self.instants.append((name, cat, time.perf_counter_ns(),
                                   threading.get_ident(), args))
 
-    def wrap(self, name: str, cat: str | None = None):
-        """Decorator form of ``span``."""
-        def deco(fn):
-            def inner(*a, **kw):
-                with self.span(name, cat):
-                    return fn(*a, **kw)
-            inner.__name__ = getattr(fn, "__name__", name)
-            return inner
-        return deco
-
     def fence(self, x):
         """Block on device work at a span boundary so async dispatch is
         billed to the span that launched it.  No-op (and no sync!) when
         tracing is disabled — instrumentation must not change the
-        untraced pipeline's host/device overlap."""
-        if self.enabled and x is not None:
+        untraced pipeline's host/device overlap.  The profiler sink does
+        not sync either: the device trace times the device work."""
+        if self.enabled and self._annotation is None and x is not None:
             import jax
             jax.block_until_ready(x)
         return x

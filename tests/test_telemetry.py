@@ -75,16 +75,23 @@ def test_disabled_tracer_allocates_nothing(monkeypatch):
             calls["n"] += 1
             super().__init__(*a, **kw)
 
+    class CountingAnnotated(tt._AnnotatedSpan):
+        def __init__(self, *a, **kw):
+            calls["n"] += 1
+            super().__init__(*a, **kw)
+
     monkeypatch.setattr(tt, "Span", CountingSpan)
-    tr = tt.Tracer(enabled=False)
-    got = [tr.span("hot", cat="x") for _ in range(100)]
-    assert calls["n"] == 0
-    assert all(g is got[0] for g in got)          # the shared singleton
-    assert got[0] is tr.span("other")             # name-independent
-    with got[0] as s:
-        assert s.set("k", "v") is s               # API parity, still no-op
-    tr.instant("nope")
-    assert tr.spans() == [] and tr.instants == []
+    monkeypatch.setattr(tt, "_AnnotatedSpan", CountingAnnotated)
+    for tr in (tt.Tracer(enabled=False),
+               tt.Tracer(enabled=False, profiler=True)):
+        got = [tr.span("hot", cat="x") for _ in range(100)]
+        assert calls["n"] == 0
+        assert all(g is got[0] for g in got)      # the shared singleton
+        assert got[0] is tr.span("other")         # name-independent
+        with got[0] as s:
+            assert s.set("k", "v") is s           # API parity, still no-op
+        tr.instant("nope")
+        assert tr.spans() == [] and tr.instants == []
     # enabled tracer DOES construct through the (patched) class
     tr_on = tt.Tracer(enabled=True)
     with tr_on.span("real"):
@@ -104,6 +111,63 @@ def test_disabled_fence_does_not_sync(monkeypatch):
     tr = tt.Tracer(enabled=True)
     tr.fence(x)
     assert hit["n"] == 1
+
+
+def _host_annotations(logdir):
+    """{line: [(name, start_ns, end_ns)]} of the ``/host:CPU`` plane of
+    the profile under ``logdir``."""
+    import glob
+    (path,) = glob.glob(str(logdir / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    (plane,) = [p for p in pd.planes if p.name == "/host:CPU"]
+    return {ln.name: [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                      for e in ln.events] for ln in plane.lines}
+
+
+def test_profiler_mode_puts_engine_spans_on_the_profiler_clock(tmp_path):
+    """In profiler mode the engine's spans are TraceAnnotations of their
+    exact names, nested as the engine opens them, on the host plane of the
+    profiler's trace; the tracer itself records nothing."""
+    cfg = get_config("granite-3-2b", reduced=True)
+    params = factory.init_params(cfg, KEY)
+    tr = tt.Tracer(enabled=True, profiler=True)
+    eng = ServeEngine(cfg, params, batch_slots=2, max_len=48, tracer=tr)
+    for rid in range(2):
+        eng.submit(Request(rid=rid, prompt=[1 + rid, 2, 3],
+                           max_new_tokens=4))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    assert tr.spans() == [] and tr.instants == []
+    assert eng.stats.decode_steps > 0
+
+    def inside(outer, inner):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    chains = 0
+    for evs in _host_annotations(tmp_path).values():
+        by = {n: [e for e in evs if e[0] == n]
+              for n in ("engine.step", "decode.step", "cache.gather")}
+        for g in by["cache.gather"]:
+            if any(inside(d, g) and any(inside(s, d)
+                                        for s in by["engine.step"])
+                   for d in by["decode.step"]):
+                chains += 1
+    assert chains == eng.stats.decode_steps
+
+
+def test_profiler_mode_fence_does_not_sync(monkeypatch):
+    hit = {"n": 0}
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: hit.__setitem__("n", hit["n"] + 1))
+    tr = tt.Tracer(enabled=True, profiler=True)
+    x = object()
+    with tr.span("decode.launch") as sp:
+        assert sp.set("slot", 0) is sp           # no-op, as on a Span
+        assert tr.fence(x) is x
+    assert hit["n"] == 0
 
 
 def test_tracer_thread_safety():
@@ -418,6 +482,35 @@ def test_engine_step_span_coverage_and_metrics():
     steps = sum(v["count"] for k, v in snap.items()
                 if k.startswith("serve_step_seconds"))
     assert steps == eng.stats.prefill_chunks + eng.stats.decode_steps
+
+
+def test_a_view_rebuild_runs_the_gather_program_once():
+    """A decode tick that gathers the cache view anew runs the cache's
+    gather program, XLA module ``jit_kv_gather_view``, exactly once, and
+    a tick that reuses the view runs it not at all: the device trace's
+    count of that program is the count of rebuilds."""
+    cfg = get_config("granite-3-2b", reduced=True)
+    params = factory.init_params(cfg, KEY)
+    eng = ServeEngine(cfg, params, batch_slots=1, max_len=48,
+                      block_size=16)
+    gather = eng.cache._gather
+    runs = []
+
+    def counted(*a):
+        runs.append(gather.lower(*a).as_text())
+        return gather(*a)
+
+    eng.cache._gather = counted
+    eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=8))
+    while eng.stats.decode_steps == 0:
+        eng.step()
+    assert len(runs) == 1               # the first tick builds the view
+    assert "module @jit_kv_gather_view" in runs[0]
+    eng.step()                          # the next row stays in block 0
+    assert len(runs) == 1
+    eng.cache.invalidate_view()
+    eng.step()
+    assert len(runs) == 2
 
 
 def test_engine_disabled_tracer_by_default():

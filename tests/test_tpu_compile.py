@@ -116,3 +116,39 @@ def test_sparse_decode_step_takes_packs_as_arguments():
                                     text)]
     assert sizes, "expected the lowered step to hold some constants"
     assert max(sizes) < smallest, (max(sizes), smallest)
+
+
+def test_decode_step_keeps_its_names_for_the_trace_readers(one_chip,
+                                                           monkeypatch):
+    """The engine's decode step, compiled for a v5e with the native
+    kernels, is still the XLA module ``jit_fn`` that launches
+    ``espim_spmv_planes`` kernels, and its ops carry the named scopes of
+    the cache update and the four pack groups in their op_name metadata
+    (the device-trace readers find programs, kernels and scopes by these
+    names)."""
+    from repro.configs.registry import get_config
+    from repro.core.sparse_model import sparsify_model
+    from repro.models import factory
+    from repro.serve.engine import ServeEngine
+
+    monkeypatch.setenv("ESPIM_FORCE_INTERPRET", "0")
+    cfg = get_config("granite-3-2b", reduced=True)
+    params = factory.init_params(cfg, jax.random.PRNGKey(0))
+    sparse = sparsify_model(cfg, params, 0.9, quant="int8")
+    eng = ServeEngine(cfg, params, batch_slots=B, max_len=64, sparse=sparse,
+                      impl="pallas")
+    view = eng.cache.gather_view(np.zeros(B, np.int32))
+    batch = {"tokens": jnp.zeros((B, 1), jnp.int32), "rng": None}
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree.map(on_chip, (eng._step_params, view, batch))
+    text = eng._decode.lower(*args).compile().as_text()
+    assert re.match(r"HloModule jit_fn\b", text)
+    assert re.search(r"^\s*%espim_spmv_planes[.\d]* = [^\n]*"
+                     r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    scopes = {part for name in re.findall(r'op_name="([^"]*)"', text)
+              for part in name.split("/")}
+    assert {"kv_cache", "espim.qkv", "espim.o", "espim.gateup",
+            "espim.down"} <= scopes

@@ -17,10 +17,12 @@ allocation can never fail.
 
 The decode/prefill steps keep the existing contiguous cache contract of
 ``models/factory.py``: ``gather_view`` materializes a (Lx, B, S_view, ...)
-view from the pages (one jitted take per leaf, cached between decode ticks
-and invalidated when block tables change), ``apply_decode`` scatters each
-active slot's newly written row back into its page, and ``scatter_chunk``
-splices a prefill chunk's rows.  A production Pallas paged-attention
+view from the pages (one jitted take per leaf), ``apply_decode`` writes
+each active slot's newly written row into its page in place, and
+``scatter_chunk`` splices a prefill chunk's rows.  The view is kept as the
+last decode step's output and rebuilt only after a write that bypasses it
+(a prefill splice, a scrub, a tick whose writes were not all committed).
+A production Pallas paged-attention
 kernel would consume the block table directly; the view keeps every model
 family working unmodified.
 
@@ -34,9 +36,12 @@ bit-parity between the two.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.configs.base import ModelConfig
 from repro.models import factory
@@ -62,6 +67,109 @@ def classify_cache(proto: dict, max_len: int):
         else:
             state.append(name)
     return seq, state
+
+
+# ------------------------------------------------------------- device programs
+# The function names become the programs' XLA module names (``jit_kv_*``),
+# which the device-trace readers match.  The three writers take the arenas
+# donated and write them with ``dynamic_update_slice`` only: XLA then
+# updates the buffers in place, where an out-of-place ``.at[].set`` scatter
+# copies every arena (twice over, through relayouts) to write a few rows.
+# A write marked dropped (physical block ``num_blocks``) reads the row at
+# the clamped index and writes it back unchanged.
+
+def _row_start(arena, blk, off):
+    return (0, blk, off) + (0,) * (arena.ndim - 3)
+
+
+@jax.jit
+def kv_gather_view(pages, block_tables):
+    """(Lx, num_blocks, bs, ...) arenas -> (Lx, B, mb * bs, ...) views."""
+    b, mb = block_tables.shape
+    out = {}
+    for n, arena in pages.items():
+        v = jnp.take(arena, block_tables.reshape(-1), axis=1)
+        out[n] = v.reshape((arena.shape[0], b, mb * arena.shape[2])
+                           + arena.shape[3:])
+    return out
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def kv_scatter_decode(pages, view, idx):
+    """Write each slot's row ``view[:, i, lens[i]]`` at ``(phys[i],
+    off[i])``.  idx: (3, B) int32 rows = (lens, phys, off), one
+    device_put per tick."""
+    lens, phys, off = idx[0], idx[1], idx[2]
+
+    def write(i, pages):
+        out = {}
+        for n, arena in pages.items():
+            row_shape = (arena.shape[0], 1, 1) + arena.shape[3:]
+            nb = arena.shape[1]
+            at = _row_start(arena, jnp.minimum(phys[i], nb - 1), off[i])
+            new = lax.dynamic_slice(view[n], _row_start(arena, i, lens[i]),
+                                    row_shape).astype(arena.dtype)
+            row = jnp.where(phys[i] < nb, new,
+                            lax.dynamic_slice(arena, at, row_shape))
+            out[n] = lax.dynamic_update_slice(arena, row, at)
+        return out
+
+    return lax.fori_loop(0, idx.shape[1], write, pages)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def kv_scatter_chunk(pages, rows, phys, span):
+    """Splice chunk rows (Lx, C, ...) into consecutive positions, one
+    whole block per write.  phys: (nblk,) physical block of each block
+    the chunk may touch, in order (``num_blocks``: drop); span: (2,) int32
+    = (offset of the chunk's first row in the first block, valid rows)."""
+    first, count = span[0], span[1]
+    nblk = phys.shape[0]
+    # rows as (Lx, 1, C, ...) with a block of padding before and enough
+    # after that block k's slice never clamps.  Built outside the loop:
+    # expanding each block's rows inside it makes XLA relayout the arenas
+    padded = {}
+    for n, arena in pages.items():
+        bs, c = arena.shape[2], rows[n].shape[1]
+        pad = [(0, 0)] * (rows[n].ndim + 1)
+        pad[2] = (bs, nblk * bs - c)
+        padded[n] = jnp.pad(jnp.expand_dims(rows[n], 1), pad)
+
+    def write(k, pages):
+        out = {}
+        for n, arena in pages.items():
+            nb, bs = arena.shape[1], arena.shape[2]
+            new = lax.dynamic_slice_in_dim(padded[n], bs + k * bs - first,
+                                           bs, axis=2).astype(arena.dtype)
+            q = k * bs + jnp.arange(bs) - first      # chunk row of each
+            keep = (q >= 0) & (q < count) & (phys[k] < nb)
+            at = _row_start(arena, jnp.minimum(phys[k], nb - 1), 0)
+            old = lax.dynamic_slice(arena, at, new.shape)
+            m = keep.reshape((1, 1, bs) + (1,) * (arena.ndim - 3))
+            out[n] = lax.dynamic_update_slice(arena, jnp.where(m, new, old),
+                                              at)
+        return out
+
+    return lax.fori_loop(0, nblk, write, pages)
+
+
+@jax.jit
+def kv_mask_state(old, new, active):
+    def leaf(o, nw):
+        m = active.reshape((1, -1) + (1,) * (o.ndim - 2))
+        return jnp.where(m, nw.astype(o.dtype), o)
+    return jax.tree.map(leaf, old, new)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def kv_scrub(pages, idx):
+    """Zero one row of every arena.  idx: (2,) int32 = (phys, off)."""
+    return {n: lax.dynamic_update_slice(
+                arena,
+                jnp.zeros((arena.shape[0], 1, 1) + arena.shape[3:],
+                          arena.dtype),
+                _row_start(arena, idx[0], idx[1]))
+            for n, arena in pages.items()}
 
 
 class _KVCacheBase:
@@ -119,54 +227,7 @@ class PagedKVCache(_KVCacheBase):
         self._quarantined: list = []    # fault-drill OOM pressure pool
         self._view = None
         self._view_dirty = True
-        self._build_jits()
-
-    # ---------------------------------------------------------------- jits
-    def _build_jits(self):
-        # the function names become the programs' XLA module names
-        # (``jit_kv_*``), which the device-trace readers match
-        lx = {n: self.pages[n].shape[0] for n in self.seq_names}
-        b, nb = self.b, self.num_blocks
-        mb, bs = self.blocks_per_slot, self.block_size
-
-        @jax.jit
-        def kv_gather_view(pages, bt_flat):
-            out = {}
-            for n, arena in pages.items():
-                v = jnp.take(arena, bt_flat, axis=1)
-                out[n] = v.reshape((lx[n], b, mb * bs) + arena.shape[3:])
-            return out
-
-        @jax.jit
-        def kv_scatter_decode(pages, view, idx):
-            # idx: (3, B) int32 rows = (lens, phys, off) — one device_put
-            # per tick instead of three
-            lens, phys, off = idx[0], idx[1], idx[2]
-            iota = jnp.arange(b)
-            out = {}
-            for n, arena in pages.items():
-                row = view[n][:, iota, lens]          # (Lx, B, ...)
-                out[n] = arena.at[:, phys, off].set(row, mode="drop")
-            return out
-
-        @jax.jit
-        def kv_scatter_chunk(pages, rows, phys, off):
-            return {n: pages[n].at[:, phys, off].set(rows[n], mode="drop")
-                    for n in pages}
-
-        @jax.jit
-        def kv_mask_state(old, new, active):
-            def leaf(o, nw):
-                m = active.reshape((1, b) + (1,) * (o.ndim - 2))
-                return jnp.where(m, nw.astype(o.dtype), o)
-            return jax.tree.map(leaf, old, new)
-
-        @jax.jit
-        def kv_scrub(pages, idx):
-            # idx: (2,) int32 = (phys, off) — zero one row of every arena
-            return {n: arena.at[:, idx[0], idx[1]].set(0)
-                    for n, arena in pages.items()}
-
+        # instance attributes, so a test can wrap one cache's programs
         self._gather = kv_gather_view
         self._scatter_decode = kv_scatter_decode
         self._scatter_chunk = kv_scatter_chunk
@@ -209,7 +270,6 @@ class PagedKVCache(_KVCacheBase):
             self.n_blocks[slot] += 1
             if self._resv[slot] > 0:
                 self._resv[slot] -= 1
-            self._view_dirty = True
 
     def free_slot(self, slot: int) -> None:
         for j in range(int(self.n_blocks[slot])):
@@ -218,7 +278,6 @@ class PagedKVCache(_KVCacheBase):
         self._resv[slot] = 0
         self.block_tables[slot] = 0
         self.zero_slot_state(slot)
-        self._view_dirty = True
 
     def quarantine_blocks(self, n: int) -> int:
         """Fault drill: withhold up to ``n`` free blocks to simulate arena
@@ -290,11 +349,16 @@ class PagedKVCache(_KVCacheBase):
     # --------------------------------------------------------------- views
     def gather_view(self, lens) -> dict:
         """Contiguous (Lx, B, view_len, ...) cache view for the jitted
-        decode step.  Rebuilt only when block tables changed; rows past a
-        slot's ``len`` may hold stale pool data — masked by attention."""
+        decode step.  Rebuilt from the pages only after a write that
+        bypassed the view (``scatter_chunk``, ``scrub_row``,
+        ``invalidate_view``); otherwise the last decode tick's output,
+        which holds every committed row at its logical position.  A new
+        block changes where later rows land in the pages, not what the
+        view holds, so block growth keeps it.  Rows past a slot's ``len``
+        may hold stale data — masked by attention."""
         if self._view_dirty or self._view is None:
-            bt = jnp.asarray(self.block_tables.reshape(-1))
-            self._view = self._gather(self.pages, bt)
+            self._view = self._gather(self.pages,
+                                      jnp.asarray(self.block_tables))
             self._view_dirty = False
         cache = dict(self._view)
         cache.update(self.state)
@@ -318,8 +382,7 @@ class PagedKVCache(_KVCacheBase):
             self.pages = self._scatter_decode(
                 self.pages, {n: new_cache[n] for n in self.seq_names}, idx)
             # the view already contains this tick's writes for every slot;
-            # inactive slots' garbage rows sit beyond their len (masked)
-            # and tables are marked dirty whenever they change
+            # inactive slots' garbage rows sit at their len (masked)
             self._view = {n: new_cache[n] for n in self.seq_names}
         if self.state_names:
             self.state = self._mask_state(
@@ -333,16 +396,21 @@ class PagedKVCache(_KVCacheBase):
         if not self.pages:
             return
         c = next(iter(rows.values())).shape[1]
-        positions = start + np.arange(c)
-        valid = np.arange(c) < count
-        logical = np.minimum(positions // self.block_size,
-                             self.blocks_per_slot - 1)
-        phys = np.where(valid, self.block_tables[slot, logical],
-                        self.num_blocks)
-        off = positions % self.block_size
+        bs = self.block_size
+        first = start % bs
+        # every block the chunk can touch (a static count per C); blocks
+        # past ``count`` rows or past the slot's table drop
+        logical = start // bs + np.arange(-(-(c + bs - 1) // bs))
+        used = ((logical * bs < start + count)
+                & (logical < self.n_blocks[slot]))
+        phys = np.where(
+            used,
+            self.block_tables[slot, np.minimum(logical,
+                                               self.blocks_per_slot - 1)],
+            self.num_blocks).astype(np.int32)
         self.pages = self._scatter_chunk(
             self.pages, {n: rows[n] for n in self.seq_names},
-            jnp.asarray(phys), jnp.asarray(off))
+            jnp.asarray(phys), jnp.asarray([first, count], jnp.int32))
         self._view_dirty = True
 
 

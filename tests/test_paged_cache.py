@@ -8,7 +8,7 @@ from repro import compat
 from repro.configs.registry import get_config
 from repro.models import factory
 from repro.serve.paged_cache import (ContiguousKVCache, PagedKVCache,
-                                     classify_cache)
+                                     classify_cache, kv_gather_view)
 from repro.sharding import partition
 
 KEY = jax.random.PRNGKey(0)
@@ -123,3 +123,102 @@ def test_paged_cache_pspecs():
     arr = jax.device_put(pc.pages["k"],
                          jax.sharding.NamedSharding(mesh, specs["k"]))
     assert arr.shape == pc.pages["k"].shape
+
+
+def _old_scatter(ref, rows, phys, off):
+    """The out-of-place scatter the cache used to run, on host copies:
+    ``arena.at[:, phys, off].set(rows, mode="drop")`` row by row."""
+    for j in range(len(phys)):
+        if phys[j] < ref.shape[1]:
+            ref[:, phys[j], off[j]] = rows[:, j]
+
+
+def _random_like(rng, shape, dtype):
+    x = rng.standard_normal(shape) * 4
+    return jnp.asarray(x.astype(np.float32)).astype(dtype)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_paged_view_and_pages_stay_in_step_across_block_growth(kv_dtype):
+    """Decode ticks that cross several block boundaries, with an inactive
+    slot, a mid-run prefill splice, a scrub and a forced invalidation.
+    At every tick the cached view equals a view rebuilt from the pages
+    below each slot's ``len``, and the in-place writes leave the pages bit
+    for bit where the old out-of-place scatter left them.  The gather runs
+    on the first tick and after a write that bypasses the view, never
+    because a slot took a new block."""
+    cfg = _cfg() if kv_dtype is None else _cfg().replace(
+        kv_cache_dtype=kv_dtype)
+    b, bs, chunk = 3, 4, 8
+    pc = PagedKVCache(cfg, batch_slots=b, max_len=40, block_size=bs)
+    gathers = []
+    real_gather = pc._gather
+    pc._gather = lambda *a: gathers.append(1) or real_gather(*a)
+    rng = np.random.default_rng(0)
+    ref = {n: np.array(a) for n, a in pc.pages.items()}
+
+    def splice(slot, start, count):
+        pc.ensure(slot, start + count)
+        rows = {n: _random_like(rng, (a.shape[0], chunk) + a.shape[3:],
+                                a.dtype) for n, a in pc.pages.items()}
+        pos = start + np.arange(chunk)
+        phys = np.where(np.arange(chunk) < count,
+                        pc.block_tables[slot, pos // bs], pc.num_blocks)
+        for n in ref:
+            _old_scatter(ref[n], np.asarray(rows[n]), phys, pos % bs)
+        pc.scatter_chunk(slot, rows, start, count)
+
+    lens = np.zeros(b, np.int32)
+    active = np.zeros(b, bool)
+    for slot, plen in ((0, 3), (1, 6)):
+        assert pc.reserve(slot, 40)
+        splice(slot, 0, plen)
+        lens[slot], active[slot] = plen, True
+    bypassed, grew = True, 0
+    for tick in range(22):
+        if tick == 5:                      # slot 2 admitted mid-run
+            assert pc.reserve(2, 40)
+            splice(2, 0, 5)
+            lens[2], active[2] = 5, True
+            bypassed = True
+        if tick == 9:
+            pc.scrub_row(0, 2)
+            for n in ref:
+                ref[n][:, pc.block_tables[0, 0], 2] = 0
+            bypassed = True
+        if tick == 12:
+            pc.invalidate_view()
+            bypassed = True
+        if tick == 15:                     # slot 1 finishes: inactive
+            pc.free_slot(1)
+            lens[1], active[1] = 0, False
+        before = pc.n_blocks.copy()
+        for i in np.flatnonzero(active):
+            pc.ensure(i, int(lens[i]) + 1)
+        grew += int((pc.n_blocks > before).any())
+        n_gathers = len(gathers)
+        view = pc.gather_view(lens)
+        assert len(gathers) - n_gathers == int(bypassed), tick
+        bypassed = False
+        rebuilt = kv_gather_view(pc.pages, jnp.asarray(pc.block_tables))
+        for n in pc.seq_names:
+            for i in range(b):
+                np.testing.assert_array_equal(
+                    np.asarray(view[n][:, i, :lens[i]]),
+                    np.asarray(rebuilt[n][:, i, :lens[i]]))
+        # the step writes a row at every slot's len, inactive ones too
+        new = dict(view)
+        for n in pc.seq_names:
+            rows = _random_like(rng, (view[n].shape[0], b)
+                                + view[n].shape[3:], view[n].dtype)
+            new[n] = view[n].at[:, np.arange(b), lens].set(rows)
+            phys = np.where(active, pc.block_tables[np.arange(b),
+                                                    lens // bs],
+                            pc.num_blocks)
+            _old_scatter(ref[n], np.asarray(rows), phys, lens % bs)
+        pc.apply_decode(new, lens, active)
+        for n in pc.seq_names:
+            np.testing.assert_array_equal(np.asarray(pc.pages[n]), ref[n])
+        lens[active] += 1
+    assert grew >= 8                       # ticks on which a slot grew
+    assert len(gathers) == 4               # first tick, splice, scrub, flush

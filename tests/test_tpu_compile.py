@@ -152,3 +152,38 @@ def test_decode_step_keeps_its_names_for_the_trace_readers(one_chip,
               for part in name.split("/")}
     assert {"kv_cache", "espim.qkv", "espim.o", "espim.gateup",
             "espim.down"} <= scopes
+
+
+# granite-3-2b's paged arenas in the benchmark's decode cell: 40 layers,
+# 8 slots x 100 blocks of 16 rows, 8 KV heads of 64, bfloat16
+ARENA = (40, 800, 16, 8, 64)
+
+
+@pytest.mark.parametrize("program", ["kv_scatter_decode",
+                                     "kv_scatter_chunk"])
+def test_paged_cache_writes_arenas_in_place(one_chip, program):
+    """The paged cache's per-tick writers, compiled for a v5e at
+    granite-3-2b's arena shapes, hold no copy of an arena and alias each
+    donated arena to its output: they write rows in place."""
+    from repro.serve import paged_cache
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = {"k": sds(ARENA), "v": sds(ARENA)}
+    if program == "kv_scatter_decode":
+        view = (ARENA[0], 8, 1600) + ARENA[3:]
+        args = (pages, {"k": sds(view), "v": sds(view)},
+                sds((3, 8), jnp.int32))
+    else:
+        rows = (ARENA[0], 256) + ARENA[3:]        # one prefill chunk
+        args = (pages, {"k": sds(rows), "v": sds(rows)},
+                sds((17,), jnp.int32), sds((2,), jnp.int32))
+    text = getattr(paged_cache, program).lower(*args).compile().as_text()
+    assert re.match(rf"HloModule jit_{program}\b", text)
+    arena = r"bf16\[" + ",".join(map(str, ARENA)) + r"\]"
+    copies = re.findall(rf"= {arena}\{{[^}}]*\}} copy\(", text)
+    assert copies == []
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", text)}
+    assert {0, 1} <= aliased          # parameters 0, 1: the two arenas

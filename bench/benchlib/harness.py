@@ -4,9 +4,11 @@
 
 The cell's entry in ``BENCHMARK.json`` names its configuration and its
 traffic mix; the harness reads them from ``bench/configs/<config>.json``,
-``bench/traffic/<mix>.json`` and ``bench/cells/<cell>.json``, and the
-per-layer metrics from ``bench/metrics/<metric>.py``.  It names none of
-them itself.
+``bench/traffic/<mix>.json`` and ``bench/cells/<cell>.json``, what
+depends on the configuration's layers (seeded weights, the reference,
+operation and byte counts) from ``bench/families/<model.family>.py``,
+and the per-layer metrics from ``bench/metrics/<metric>.py``.  It names
+none of them itself.
 
 A run: refuse to start without a TPU of a kind in ``bench/peaks.json``;
 make the weights from the configuration's ``weight_seed`` on the device;
@@ -22,13 +24,16 @@ first run) and warm-up.  Building the packs on a cache miss, and saving
 them, is the offline step a deployment makes once per configuration and
 is printed apart, not counted in it.
 
-``--trace 1`` spends the first half of the window under the profiler
-(the program's own tracer off, since its fences add host syncs) and the
-second half with the program's tracer on, and prints per-layer metrics.
+``--trace 1`` spends the first half of the window under the profiler,
+with the program's tracer writing its spans into the profiler's trace
+(no fences), and the second half with the program's fenced tracer, and
+prints per-layer metrics.  A reader gets the trace, the program's spans
+of both halves and its counters over the first half.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import gc
 import importlib.util
 import json
@@ -39,7 +44,6 @@ import time
 
 import numpy as np
 
-from benchlib import flops as F
 from benchlib import packcache, stats, trace_reduce
 from benchlib.traffic import Traffic
 
@@ -70,6 +74,11 @@ class Cell:
         self.config_bytes = self.config_path.read_bytes()
         self.config = json.loads(self.config_bytes)
         self.model = self.config["model"]
+        family = self.model["family"]
+        self.family = root / "bench" / "families" / f"{family}.py"
+        if not self.family.is_file():
+            raise RunError(f"no family module for {family!r}: add "
+                           f"bench/families/{family}.py")
         self.mix = json.loads((root / "bench" / "traffic" /
                                f"{self.entry['traffic']}.json").read_text())
         self.cell = json.loads((root / "bench" / "cells" /
@@ -121,7 +130,9 @@ class _Compiles:
 
 def _check_model(cfg, m: dict) -> None:
     """The program's config must be the one the configuration file (and
-    so the reference) describes."""
+    so the reference) describes: the keys below must be in the file, and
+    every key of the file that names a field of the program's config
+    must agree with it (``head_dim`` with the head size in use)."""
     got = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
            "head_dim": cfg.hd, "d_ff": cfg.d_ff,
@@ -132,7 +143,9 @@ def _check_model(cfg, m: dict) -> None:
            "compute_dtype": cfg.compute_dtype, "family": cfg.family,
            "qkv_bias": cfg.qkv_bias, "norm": cfg.norm,
            "kv_cache_dtype": cfg.kv_cache_dtype}
-    bad = {k: (v, m.get(k)) for k, v in got.items()
+    fields = {f.name for f in dataclasses.fields(cfg)} - set(got)
+    stated = {k: getattr(cfg, k) for k in m if k in fields}
+    bad = {k: (v, m.get(k)) for k, v in {**got, **stated}.items()
            if k in m and m[k] != v}
     missing = sorted(set(got) - set(m))
     if bad or missing:
@@ -206,9 +219,33 @@ class Client:
                    for s in self.eng.slots)
 
 
+def registry_values(reg) -> dict:
+    """{series: (kind, value)} of every counter and gauge in the program's
+    metrics registry.  A series is the instrument's name with the labels
+    it adds to the registry's own, as ``name{key="value",...}``."""
+    out = {}
+    for name, fam in reg._metrics.items():
+        for inst in fam.values():
+            if inst.kind not in ("counter", "gauge"):
+                continue
+            lab = {k: v for k, v in inst.labels.items()
+                   if reg.base_labels.get(k) != v}
+            key = ",".join(f'{k}="{lab[k]}"' for k in sorted(lab))
+            out[f"{name}{{{key}}}" if key else name] = (inst.kind,
+                                                         inst.value)
+    return out
+
+
+def window_counters(start: dict, end: dict) -> dict:
+    """From two ``registry_values`` readings: each counter's rise from
+    ``start`` to ``end``, and each gauge's value at ``end``."""
+    return {k: v - start.get(k, (kind, 0))[1] if kind == "counter" else v
+            for k, (kind, v) in end.items()}
+
+
 def _load_reader(path: pathlib.Path):
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{path.stem.replace('.', '_')}", path)
+        f"bench_{path.parent.name}_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -278,15 +315,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     from repro.serve.engine import Request, ServeEngine
     from repro.telemetry import trace as tt
 
-    from benchlib import reference, weights
+    from benchlib import reference
 
+    fam = _load_reader(cell.family)
     conf, m, c = cell.config, cell.model, cell.cell
     wseed = int(conf["weight_seed"])
     secs = {}
     t = time.perf_counter()
     cfg = get_config(conf["arch"]).replace(**conf.get("overrides", {}))
     _check_model(cfg, m)
-    params = weights.make_params(m, wseed)
+    params = fam.make_params(m, wseed)
     jax.block_until_ready(params)
     secs["init"] = time.perf_counter() - t
     print(f"weights made: device memory {mem()}", file=sys.stderr, flush=True)
@@ -314,14 +352,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     if require_tpu and (prov["impl"] != "pallas" or prov["pallas_interpret"]):
         raise RunError(f"the engine would not run the native kernels: {prov}")
     secs["engine"] = time.perf_counter() - t
-    step_bytes = F.espim_step_bytes(sparse, int(c["slots"]))
-    step_ops = F.espim_step_ops(sparse, int(c["slots"]))
+    step_bytes = fam.espim_step_bytes(sparse, int(c["slots"]))
+    step_ops = fam.espim_step_ops(sparse, int(c["slots"]))
     if fault is not None:
         fault(eng)
 
     sparsity, projections = float(conf["sparsity"]), conf["projections"]
-    f0 = F.token_flops(m, sparsity, projections, 0)
-    f1 = F.token_flops(m, sparsity, projections, 1) - f0
+    f0 = fam.token_flops(m, sparsity, projections, 0)
+    f1 = fam.token_flops(m, sparsity, projections, 1) - f0
 
     def tok_flops(ctx):
         return f0 + f1 * ctx
@@ -372,9 +410,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     steps0 = (eng.stats.decode_steps, eng.stats.prefill_chunks)
     flops0, flops_a = client.flops, None
     t_half = t0 + seconds / 2 if trace else None
-    tracer = None
+    tracer = counters0 = counters = None
     win = jax.profiler.TraceAnnotation("bench.window") if trace else None
     if win:
+        counters0 = registry_values(eng.metrics)
+        eng.tracer = tt.Tracer(enabled=True, profiler=True)
         win.__enter__()
     t_a = None
     now = t0
@@ -386,6 +426,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             win.__exit__(None, None, None)
             t_a = now
             flops_a = client.flops - flops0
+            counters = window_counters(counters0,
+                                       registry_values(eng.metrics))
             tracer = tt.Tracer(enabled=True)
             eng.tracer = tracer
         if open_loop:
@@ -480,6 +522,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
                "peak": peak, "trace": tr, "window_ns": w,
                "window_s": t_a - t0, "flops": flops_a,
                "spans": tracer.spans() if tracer else [],
+               "program": trace_reduce.events_in(tr["program"], w),
+               "counters": counters or {},
                "espim_step_bytes": step_bytes, "espim_step_ops": step_ops}
         for mt in cell.per_layer:
             rd = _load_reader(BENCH / "metrics" / f"{mt['name']}.py")
@@ -508,8 +552,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
                     "witness": {"bits": bits, "act": m["compute_dtype"]}}
     got = {}
     if rows:
-        got = reference.readings(m, wseed, sparsity, projections, rows,
-                                 int(c["max_len"]), variants)
+        got = fam.readings(m, wseed, sparsity, projections, rows,
+                           int(c["max_len"]), variants)
         for r, rid in enumerate(sample):
             desc = ", ".join(
                 f"{k} mean {g[r].mean():.4g} max {g[r].max():.4g} at "

@@ -10,7 +10,13 @@ keeps what the metrics read, in a small JSON-able form:
   Modules`` line (one per compiled program run), each ``[name,
   start_ns, duration_ns]``;
 * ``host``: the benchmark's own ``jax.profiler.TraceAnnotation`` spans
-  (names starting with ``bench.``), on the same clock.
+  (names starting with ``bench.``), on the same clock;
+* ``program``: the program's own spans, which its tracer writes as
+  ``TraceAnnotation``s in the profiler's mode, each ``[name, start_ns,
+  duration_ns]``: every host event named as the program names its spans,
+  dotted lower-case words such as ``engine.step`` or ``cache.gather``
+  (the runtime's own host events, the Python tracer's ``$``-prefixed
+  calls and the CPU backend's ops, such as ``dot_general.1``, are not).
 
 The functions below take that form, so a recorded trace committed with
 the benchmark checks them without a chip.
@@ -27,6 +33,7 @@ __all__ = ["extract", "find_xplane", "window", "union", "busy_ns",
            "idle_gaps", "under", "self_times"]
 
 OPS, MODULES = "XLA Ops", "XLA Modules"
+PROGRAM_SPAN = re.compile(r"[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+")
 
 
 def find_xplane(logdir: str) -> str:
@@ -40,7 +47,7 @@ def find_xplane(logdir: str) -> str:
 def extract(path: str, host_prefix: str = "bench.") -> dict:
     import jax
     pd = jax.profiler.ProfileData.from_file(path)
-    out = {"device": {}, "host": []}
+    out = {"device": {}, "host": [], "program": []}
     for plane in pd.planes:
         if re.fullmatch(r"/device:TPU:\d+", plane.name):
             lines = {}
@@ -55,8 +62,13 @@ def extract(path: str, host_prefix: str = "bench.") -> dict:
             for line in plane.lines:
                 for ev in line.events:
                     if ev.name.startswith(host_prefix):
-                        out["host"].append([ev.name, float(ev.start_ns),
-                                            float(ev.duration_ns)])
+                        key = "host"
+                    elif PROGRAM_SPAN.fullmatch(ev.name):
+                        key = "program"
+                    else:
+                        continue
+                    out[key].append([ev.name, float(ev.start_ns),
+                                     float(ev.duration_ns)])
     return out
 
 

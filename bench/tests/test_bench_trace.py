@@ -4,7 +4,9 @@ of the decode-backlog cell in the extracted form that
 ``trace_reduce.extract`` returns, cut from the profile a ``--trace 1`` run
 leaves under ``bench/.cache/trace/<cell>``, which
 ``trace_reduce.extract(trace_reduce.find_xplane(dir))`` reads), checked
-against plain re-computations.
+against plain re-computations; and ``extract`` itself on a profile
+recorded on the CPU around a few annotations, the program's apart from
+the benchmark's.
 """
 from __future__ import annotations
 
@@ -130,3 +132,75 @@ def test_idle_gaps_add_up_to_the_idle_time(tr):
 
 def test_union_merges_overlaps_and_touching_intervals():
     assert R.union([(5, 6), (1, 3), (2, 4), (4, 4.5)]) == [[1, 4.5], [5, 6]]
+
+
+PROGRAM_SPANS = ("engine.step", "decode.step", "cache.gather")
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """A profile recorded here on the CPU, as ``extract`` reads it: two
+    benchmark steps, each around the program's nested spans and a jitted
+    call, inside the benchmark's window."""
+    import jax
+    import jax.numpy as jnp
+    logdir = tmp_path_factory.mktemp("profile")
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((64, 64))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(logdir))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            for _ in range(2):
+                with jax.profiler.TraceAnnotation("bench.step"), \
+                        jax.profiler.TraceAnnotation("engine.step"), \
+                        jax.profiler.TraceAnnotation("decode.step"):
+                    with jax.profiler.TraceAnnotation("cache.gather"):
+                        f(x).block_until_ready()
+                    float(f(x))
+    finally:
+        jax.profiler.stop_trace()
+    return R.extract(R.find_xplane(str(logdir)))
+
+
+def test_extract_keeps_the_program_spans_apart(recorded):
+    prog = recorded["program"]
+    assert sorted(n for n, _, _ in prog) == sorted(PROGRAM_SPANS * 2)
+    assert sorted(n for n, _, _ in recorded["host"]) == [
+        "bench.step", "bench.step", "bench.window"]
+    # no accelerator here: no device plane
+    assert recorded["device"] == {}
+    w = R.window(recorded)
+    assert R.events_in(prog, w) == prog
+
+
+def test_program_spans_take_the_form_of_the_recorded_chip_trace(recorded):
+    with gzip.open(DATA.with_name("decode_trace_program.json.gz"),
+                   "rt") as f:
+        chip = json.load(f)["program"]
+    for got in (recorded["program"], chip):
+        assert got and all(
+            len(e) == 3 and isinstance(e[0], str)
+            and all(isinstance(v, float) for v in e[1:]) and e[2] > 0
+            for e in got)
+    # nested as opened: each cache.gather inside a decode.step inside an
+    # engine.step inside a bench.step
+    prog = recorded["program"]
+
+    def inside(outer, inner):
+        return outer[1] <= inner[1] and inner[1] + inner[2] <= \
+            outer[1] + outer[2]
+    steps = [e for e in recorded["host"] if e[0] == "bench.step"]
+    for g in (e for e in prog if e[0] == "cache.gather"):
+        (d,) = [e for e in prog if e[0] == "decode.step" and inside(e, g)]
+        (s,) = [e for e in prog if e[0] == "engine.step" and inside(e, d)]
+        assert any(inside(b, s) for b in steps)
+
+
+@pytest.mark.parametrize("name, kept", [
+    ("engine.step", True), ("cache.scatter", True),
+    ("decode.launch_degraded", True), ("dot_general.1", False),
+    ("end: dot_general.1", False), ("$numpy asarray", False),
+    ("PjitFunction(fn)", False), ("jit_fn", False)])
+def test_which_host_events_count_as_program_spans(name, kept):
+    assert bool(R.PROGRAM_SPAN.fullmatch(name)) is kept

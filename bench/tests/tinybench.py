@@ -1,7 +1,7 @@
 """A tiny benchmark layout for the CPU tests: the same harness, a
 granite-family configuration cut to a few layers of small width, and the
 two traffic mixes at toy lengths, written under a temporary root that
-links the program's sources."""
+links the program's sources and the family modules."""
 from __future__ import annotations
 
 import json
@@ -36,13 +36,19 @@ MIXES = {
 
 
 def make(root: pathlib.Path, quant: str = "int8", limits: dict | None = None,
-         rate: float = 20.0) -> pathlib.Path:
-    """Write the layout under ``root``; returns ``root``."""
+         rate: float = 20.0,
+         families: pathlib.Path | None = None) -> pathlib.Path:
+    """Write the layout under ``root``; returns ``root``.  ``families``
+    is the directory of family modules the layout links (the benchmark's
+    own by default)."""
     (root / "bench" / "configs").mkdir(parents=True, exist_ok=True)
     (root / "bench" / "cells").mkdir(exist_ok=True)
     (root / "bench" / "traffic").mkdir(exist_ok=True)
     if not (root / "src").exists():
         os.symlink(REPO / "src", root / "src")
+    if not (root / "bench" / "families").exists():
+        os.symlink(families or BENCH / "families",
+                   root / "bench" / "families")
     conf = {"name": "tiny", "arch": "granite-3-2b", "overrides": OVERRIDES,
             "model": MODEL, "reduced": [], "sparsity": 0.9, "quant": quant,
             "projections": "all", "weight_seed": 7}
